@@ -12,7 +12,7 @@ update writes only the moved rows.
 ``donated_update`` is that path: a jitted ``donate_argnums=(0,)``
 scatter shared by ``InterleavedTensor._scatter_bucketed``, the
 stable-path ``repartition``, and ``TieredKVCache._retile``.  On this
-CPU backend (jax >= 0.4.3x) donation is real — the output aliases the
+CPU backend donation is real — the output aliases the
 input buffer (asserted by tests/test_actuation.py via
 ``unsafe_buffer_pointer``) — and on TPU/GPU it is the standard aliasing
 path.  Index arrays are padded to power-of-two buckets (out-of-range
@@ -43,6 +43,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compute_on import compute_on
 
 
 class CopyCounter:
@@ -90,12 +91,15 @@ def pad_to_bucket(rows: np.ndarray, values, n_rows: int
     return rows_p, vals_p
 
 
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("op",))
-def _donated_row_update(part, rows, values, op: str = "set"):
+def _row_update(part, rows, values, op: str):
     ref = part.at[rows]
     if op == "set":
         return ref.set(values, mode="drop")
     return ref.add(values, mode="drop")
+
+
+_donated_row_update = jax.jit(_row_update, donate_argnums=(0,),
+                              static_argnames=("op",))
 
 
 @functools.lru_cache(maxsize=64)
@@ -103,11 +107,16 @@ def _donated_row_update_sharded(op: str, sharding):
     # memory_kind backends must keep the output in the donated input's
     # memory space — out_shardings pins it (a bare jit could migrate the
     # result back to default device memory, silently un-tiering the shard).
+    # XLA cannot scatter into pinned_host memory from the device, so a
+    # pinned_host shard is patched on the host: only rows and values
+    # cross the link, and the donated buffer is updated in place.
     def fn(part, rows, values):
-        ref = part.at[rows]
-        if op == "set":
-            return ref.set(values, mode="drop")
-        return ref.add(values, mode="drop")
+        if sharding.memory_kind != "pinned_host":
+            return _row_update(part, rows, values, op)
+        rows = jax.device_put(rows, jax.memory.Space.Host)
+        values = jax.device_put(values, jax.memory.Space.Host)
+        with compute_on("device_host"):
+            return _row_update(part, rows, values, op)
 
     return jax.jit(fn, donate_argnums=(0,), out_shardings=sharding)
 

@@ -40,8 +40,11 @@ Memory backends (ISSUE 7 — ``backend=`` on :meth:`from_array`):
   executor moves them (HBM -> VMEM staging -> HBM, overlapped DMAs).
 * ``memory_kind`` — slow shards physically live in ``pinned_host``
   memory via JAX memory-kind shardings; fast stays in ``device``.
-  Requires a runtime exposing pinned-host memory (TPU/GPU); on CPU it
-  falls back to ``modeled`` (``resolve_backend`` / ``"auto"``).
+  Requires a runtime exposing pinned-host memory (TPU/GPU); asking for
+  it elsewhere raises (``"auto"`` picks ``modeled`` there).  XLA cannot
+  compute on a ``pinned_host`` operand from the device, so reads of a
+  slow shard gather the touched rows on the host and move only those to
+  HBM, and donated writes scatter on the host in place.
 
 Donation (``donate=`` on the writers/repartitioners): when the caller
 provably drops the parent tensor, the stable-path update runs through a
@@ -58,6 +61,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compute_on import compute_on
 
 from repro.core.donation import FULL_SHARD_COPIES, donated_update
 from repro.core.ledger import TierLedger
@@ -69,41 +73,55 @@ BACKENDS = ("modeled", "staged", "memory_kind")
 
 
 def supports_memory_kinds() -> bool:
-    """True when the runtime exposes a ``pinned_host`` memory space
-    (TPU/GPU runtimes); plain CPU only has ``unpinned_host``."""
-    try:
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-    except Exception:
-        return False
-    return "pinned_host" in kinds
+    """True when the device has memory of its own and the runtime also
+    exposes a ``pinned_host`` space to tier into (TPU/GPU runtimes).  A
+    CPU device's memory already is host memory, so a ``pinned_host``
+    space there is no slower tier."""
+    device = jax.devices()[0]
+    kinds = {m.kind for m in device.addressable_memories()}
+    return device.platform != "cpu" and "pinned_host" in kinds
 
 
 def resolve_backend(backend: str = "auto") -> str:
     """Resolve a requested backend to one this runtime can honour.
 
-    ``auto`` and ``memory_kind`` degrade to ``modeled`` when the runtime
-    has no pinned-host memory space (the CPU-only fallback the README
-    backend matrix documents); ``modeled``/``staged`` pass through."""
-    if backend in ("auto", "memory_kind"):
+    ``auto`` picks ``memory_kind`` where the runtime has a pinned-host
+    memory space and ``modeled`` elsewhere.  An explicit ``memory_kind``
+    raises where it cannot be honoured rather than quietly modeling the
+    tiers; ``modeled``/``staged`` pass through."""
+    if backend == "auto":
         return "memory_kind" if supports_memory_kinds() else "modeled"
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; want one of "
                          f"{BACKENDS + ('auto',)}")
+    if backend == "memory_kind" and not supports_memory_kinds():
+        raise ValueError(
+            "backend 'memory_kind' needs a pinned_host memory space, which "
+            f"the {jax.default_backend()} runtime does not expose")
     return backend
 
 
-def _place_part(part: jax.Array, ordinal: int, backend: str) -> jax.Array:
-    """Pin a shard to its memory kind: ``device`` for the fast tier,
-    ``pinned_host`` for slow devices (``memory_kind`` backend only)."""
+def _place_part(part, ordinal: int, backend: str) -> jax.Array:
+    """A shard (jax or numpy) as a jax array in its memory kind: ``device``
+    for the fast tier, ``pinned_host`` for slow devices (``memory_kind``
+    backend only; the others keep default device memory)."""
     if backend != "memory_kind":
-        return part
-    try:
-        dev = next(iter(part.devices()))
-    except Exception:
-        dev = jax.devices()[0]
+        return jnp.asarray(part)
     kind = "device" if ordinal == 0 else "pinned_host"
-    sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0],
+                                                 memory_kind=kind)
     return jax.device_put(part, sharding)
+
+
+@jax.jit
+def _host_rows(part: jax.Array, idx: jax.Array) -> jax.Array:
+    """``part[idx]`` in HBM for a shard held in ``pinned_host`` memory: the
+    gather runs on the host, and only the gathered rows cross to HBM."""
+    idx = jax.device_put(idx, jax.memory.Space.Host)
+    with compute_on("device_host"):
+        rows = jnp.take(part, idx, axis=0, mode="clip")
+    return jax.device_put(rows, jax.memory.Space.Device)
+
 
 #: default movement-run length (pages) the minimal-move planner clusters
 #: its picks into: one mover Descriptor ships one run, so a Δ-page shift
@@ -539,7 +557,10 @@ class InterleavedTensor:
         for i, part in enumerate(self.parts):
             if part.shape[0] == 0:
                 continue
-            got = jnp.take(part, local, axis=0, mode="clip")
+            if self._on_host(i):
+                got = _host_rows(part, local)
+            else:
+                got = jnp.take(part, local, axis=0, mode="clip")
             if out is None:
                 out = got
             else:
@@ -603,6 +624,10 @@ class InterleavedTensor:
                          else ref.add(values, mode="drop"))
         return dataclasses.replace(self, parts=tuple(parts))
 
+    def _on_host(self, i: int) -> bool:
+        """True when shard ``i`` lives in ``pinned_host`` memory."""
+        return self.backend == "memory_kind" and i > 0
+
     def _donate_sharding(self, i: int):
         """out_sharding pin for donated updates (memory_kind shards only)."""
         if self.backend != "memory_kind":
@@ -655,7 +680,7 @@ class InterleavedTensor:
                 new_part[rows[keep]] = vals[mask][keep]
             else:
                 np.add.at(new_part, rows[keep], vals[mask][keep])
-            parts[i] = jnp.asarray(new_part)
+            parts[i] = _place_part(new_part, i, self.backend)
             mirrors[i] = new_part
         out = dataclasses.replace(self, parts=tuple(parts))
         out._with_parts_host(mirrors)
@@ -685,7 +710,9 @@ class InterleavedTensor:
         ``reduce_fn(table, indices, weights) -> (batch, feature)`` lets the
         Pallas ``embedding_reduce`` kernel slot in; default is pure jnp.
         Rows owned by another device contribute weight 0 to each shard, so
-        the per-shard partials sum to the un-tiered reduction exactly.
+        the per-shard partials sum to the un-tiered reduction exactly.  A
+        ``pinned_host`` shard first gathers the looked-up rows on the host;
+        ``reduce_fn`` then reduces those rows in HBM.
         """
         if weights is None:
             weights = jnp.ones(indices.shape, self.parts[0].dtype)
@@ -698,6 +725,9 @@ class InterleavedTensor:
                 continue
             w_i = jnp.where(dev == i, weights, 0).astype(part.dtype)
             local_i = jnp.minimum(local, part.shape[0] - 1)
+            if self._on_host(i):
+                part = _host_rows(part, local_i.reshape(-1))
+                local_i = jnp.arange(local_i.size).reshape(local_i.shape)
             partial = reduce_fn(part, local_i, w_i)
             out = partial if out is None else out + partial
         if out is None:  # zero-row tensor
@@ -832,11 +862,12 @@ class InterleavedTensor:
             pr = self.page_rows
 
             def slab(s: int, l0: int, n_pages: int):
-                # modeled backend ships zero-copy host-mirror views; the
-                # staged / memory_kind backends keep the slab device-side
-                # so the mover's double-buffered stream_copy executor is
-                # the thing that actually moves it.
-                if self.backend == "modeled":
+                # modeled backend ships zero-copy host-mirror views, and
+                # so do pinned_host shards (the page gather mirrors them
+                # anyway); device shards of the staged / memory_kind
+                # backends keep the slab device-side so the mover's
+                # double-buffered stream_copy executor actually moves it.
+                if self.backend == "modeled" or self._on_host(s):
                     return self._part_host(s)[l0 * pr: (l0 + n_pages) * pr]
                 return self.parts[s][l0 * pr: (l0 + n_pages) * pr]
 
@@ -975,7 +1006,7 @@ class InterleavedTensor:
                 (-1, pr) + data.shape[2:])
             new_part[slots] = data
             new_flat = new_part.reshape((-1,) + data.shape[2:])
-            parts[int(d)] = jnp.asarray(new_flat)
+            parts[int(d)] = _place_part(new_flat, int(d), self.backend)
             mirrors[int(d)] = new_flat
         out = dataclasses.replace(
             self,
@@ -1005,8 +1036,7 @@ class InterleavedTensor:
             cap = counts[d] + self.headroom
             if cap == 0:
                 empty = np.zeros((0,) + tuple(feature), dtype)
-                parts.append(_place_part(jnp.asarray(empty), d,
-                                         self.backend))
+                parts.append(_place_part(empty, d, self.backend))
                 mirrors.append(empty)
                 continue
             pages_d = np.nonzero(dev2 == d)[0]  # page-id order == rank order
@@ -1015,7 +1045,7 @@ class InterleavedTensor:
                                                    old_local)
             flat = data.reshape((-1,) + tuple(feature))
             FULL_SHARD_COPIES.bump()
-            parts.append(_place_part(jnp.asarray(flat), d, self.backend))
+            parts.append(_place_part(flat, d, self.backend))
             mirrors.append(flat)
         out = dataclasses.replace(
             self,

@@ -143,8 +143,8 @@ def stream_executor(block_rows: int = 256, *, block_bytes_hint: int = 1 << 20
     Pallas ``stream_copy`` migration kernel (HBM -> VMEM staging -> HBM
     with overlapped async DMAs and the dtype cast fused in VMEM).
 
-    2-D jax leaves take the kernel directly; higher-rank jax leaves are
-    viewed as (rows, features) first (a free reshape); host numpy leaves
+    2-D jax leaves take the kernel directly; other jax leaves are viewed
+    as (rows, last dim) first (a free reshape); host numpy leaves
     keep the memcpy path — there is no DMA engine to overlap on host
     memory, and XLA dispatch would dominate.  The returned callable is
     flagged ``pipelined`` so ``BulkMover.modeled_cost`` switches to the
@@ -159,7 +159,10 @@ def stream_executor(block_rows: int = 256, *, block_bytes_hint: int = 1 << 20
             x = jnp.asarray(x)
             if x.ndim == 0 or x.size == 0:
                 return x.astype(out_dtype) if out_dtype else x.copy()
-            flat = x.reshape(x.shape[0], -1) if x.ndim != 2 else x
+            # (rows, last dim): a wide flattened row would not fit the
+            # kernel's VMEM staging buffers.
+            flat = (x.reshape(-1, x.shape[-1]) if x.ndim > 2
+                    else x.reshape(x.shape[0], -1))
             out = _stream_ops.stream_copy(flat, out_dtype=out_dtype,
                                           block_rows=block_rows)
             return out.reshape(x.shape)

@@ -14,6 +14,8 @@ import dataclasses
 import enum
 from typing import Optional
 
+import jax
+
 GiB = 1024**3
 GB = 1e9
 
@@ -189,6 +191,8 @@ TPU_ICI_LINKS_PER_CHIP = 4  # v5e 2D torus: 4 links
 TPU_DCN_BW_PER_HOST = 12.5 * GB  # cross-pod (pod axis) effective
 TPU_PCIE_BW = 32 * GB  # host<->chip effective (the "CXL" link)
 TPU_CHIPS_PER_HOST = 8
+#: ``device_kind`` of the chip these constants describe.
+TPU_V5E_DEVICE_KINDS = ("TPU v5 lite", "TPU v5e")
 
 HBM_V5E = TierSpec(
     name="hbm",
@@ -368,7 +372,14 @@ def paper_three_device_topology() -> TierTopology:
 
 
 def tpu_v5e_topology() -> TierTopology:
-    """Deployment target: HBM fast tier + host-DRAM-behind-PCIe slow tier."""
+    """Deployment target: HBM fast tier + host-DRAM-behind-PCIe slow tier.
+
+    Raises on a TPU of another kind, whose peaks these are not."""
+    if jax.default_backend() == "tpu":
+        kind = jax.devices()[0].device_kind
+        if kind not in TPU_V5E_DEVICE_KINDS:
+            raise ValueError(f"the tpu-v5e tier preset describes a TPU v5e; "
+                             f"this chip is {kind!r}")
     return TierTopology(fast=HBM_V5E, slow=HOST_V5E)
 
 

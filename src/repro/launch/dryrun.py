@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces, without allocating any model-scale array:
@@ -13,10 +11,14 @@ For each cell this produces, without allocating any model-scale array:
 Usage:
   python -m repro.launch.dryrun --arch qwen2.5-32b --shape train_4k
   python -m repro.launch.dryrun --all [--mesh both] [--out experiments/dryrun]
+
+``main`` gives the CPU backend 512 virtual devices through ``XLA_FLAGS``
+before JAX starts; importing this module leaves the flags alone.
 """
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -161,8 +163,6 @@ def lower_cell(arch_id: str, shape: ShapeSpec, mesh, *, n_micro: int = 0,
         }
         record["fits_hbm"] = record["memory"]["peak_per_device"] <= HBM_PER_CHIP
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     record["cost_analysis"] = {
         "flops": float(ca.get("flops", -1)),
         "bytes_accessed": float(ca.get("bytes accessed", -1)),
@@ -213,6 +213,8 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, out_dir: str,
 
 
 def main():
+    # Must precede the first JAX backend use of the process.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -2,17 +2,11 @@
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # AxisType landed after jax 0.4.x; Auto is the default there anyway.
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+def _axis_kwargs(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,11 +23,8 @@ def make_mesh(shape, axes):
 
 
 def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available; the Mesh object itself is a
-    context manager on older jax (pjit-style), with the same scoping."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Scope ``mesh`` as the ambient mesh (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -19,11 +19,16 @@ from repro.core.mover import BulkMover
 from repro.core.policy import MemPolicy
 from repro.core.tiers import topology_from_spec
 from repro.core.warmstart import WarmStartMemo
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get as get_arch
 from repro.serving.engine import ServingEngine, kv_access_profile
 
 
-def main(argv=None):
+def main(argv=None, params=None):
+    """Serve ``--requests`` random prompts; returns the finished requests.
+
+    ``params`` are the weights to serve; by default they are random,
+    drawn from ``PRNGKey(0)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-32b")
     ap.add_argument("--tiny", action="store_true")
@@ -71,6 +76,7 @@ def main(argv=None):
                          "pages) in a TierLedger and print the per-tier "
                          "capacity report after the run")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.tiny:
@@ -78,7 +84,8 @@ def main(argv=None):
     cfg = arch.cfg
     if cfg.family not in ("dense", "vlm", "moe"):
         raise SystemExit("tiered serving demo targets uniform-attention archs")
-    params = arch.module.init(cfg, jax.random.PRNGKey(0))
+    if params is None:
+        params = arch.init(jax.random.PRNGKey(0))
     topology = topology_from_spec(args.devices)
     if topology.n_slow > 1:
         # Seed the per-device split bandwidth-proportionally (Fig. 10's

@@ -12,6 +12,8 @@ Usage (CPU example — a ~100M model for a few hundred steps):
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 import time
 
 import jax
@@ -28,6 +30,7 @@ from repro.core.warmstart import WarmStartMemo
 from repro.core.planner import BufferReq, plan as plan_placement
 from repro.core.policy import BufferClass
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get as get_arch
 from repro.optim import adamw, offload, schedules
 from repro.runtime.straggler import StragglerMitigator
@@ -43,7 +46,7 @@ def build(arch_id: str, *, tiny: bool, batch: int, seq: int, lr: float,
     opt_cfg = adamw.AdamWConfig(
         lr=lr, schedule=schedules.warmup_cosine(min(100, total_steps // 10),
                                                 total_steps))
-    params = arch.module.init(cfg, jax.random.PRNGKey(0))
+    params = arch.init(jax.random.PRNGKey(0))
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
 
     # Paper integration: plan optimizer-state placement against the target
@@ -89,7 +92,8 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--offload-fraction", type=float, default=None)
     ap.add_argument("--devices", default="tpu-v5e",
@@ -111,6 +115,7 @@ def main(argv=None):
                     help="paired probe duels per Caption candidate point "
                          "(noise-robust probing); 0 = single-sample")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch, opt_cfg, opt, params, opt_state, n_params, placement, topo = build(
         args.arch, tiny=args.tiny, batch=args.batch, seq=args.seq,

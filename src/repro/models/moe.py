@@ -179,15 +179,8 @@ def moe_mlp_ep(x2d: jax.Array, p: dict, spec: MoESpec, act: str,
         k: P(dp, None, None) for k in p["experts"]
     })
     out_specs = (P(dp, None), P(), P(), P(), P())
-    if hasattr(jax, "shard_map"):
-        smap = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(dp))
-    else:  # jax 0.4.x: no partial-manual axes; every axis is manual, so
-        # outputs replicated over the unmentioned model axis need
-        # check_rep off (they are replicated by construction).
-        from jax.experimental.shard_map import shard_map as _shard_map
-        smap = _shard_map(local, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    smap = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(dp))
     y, lb, zl, dropped, counts = smap(x2d, p["router"], p["experts"])
     if spec.n_shared:
         y = y + mlp_apply(x2d, p["shared"], act)

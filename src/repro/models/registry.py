@@ -11,6 +11,8 @@ import dataclasses
 import importlib
 from types import ModuleType
 
+import jax
+
 from repro.configs.base import ArchConfig
 
 _CONFIG_MODULES = {
@@ -49,6 +51,13 @@ class Arch:
 
     def tiny(self) -> "Arch":
         return Arch(self.cfg.tiny(), self.module)
+
+    def init(self, key) -> dict:
+        """Random parameters, built under ``jit`` so each weight is drawn
+        straight into its dtype: eager init materializes every stacked
+        layer weight in float32 first, which does not fit one chip's HBM
+        at full width."""
+        return jax.jit(self.module.init, static_argnums=0)(self.cfg, key)
 
 
 def get_config(arch_id: str) -> ArchConfig:

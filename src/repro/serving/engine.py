@@ -180,6 +180,8 @@ class ServingEngine:
         self.queue: list[Request] = []
         self._next_rid = 0
         self.done: list[Request] = []
+        #: (max_batch, vocab) logits of the latest decode step.
+        self.last_logits: Optional[jax.Array] = None
         # modeled per-step seconds: per-tier KV streaming on the target HW
         self._step_model_cache: Optional[dict] = None
         # Caption control loop: between decode steps the controller reads
@@ -572,6 +574,7 @@ class ServingEngine:
             return 0
         logits, self.cache = self._decode(
             self.params, self.cache, self._current_tokens())
+        self.last_logits = logits
         step_model_s = self.modeled_step_seconds()
         now = time.perf_counter()
         toks = sample_greedy(logits)

@@ -2,10 +2,10 @@
 
 The KV time axis is split into pages placed across (fast, slow...) devices
 by a MemPolicy — the paper's N:M weighted interleave applied to serving
-state.  Decode attends over every device partition and merges exactly via
-log-sum-exp (attention.merge_partials); per-step per-tier byte counts
-feed the perfmodel so benchmarks reproduce the paper's p99/QPS curves
-on this CPU-only box.
+state.  Decode reads each page from the device pool that holds it, in
+position order (``TieredKVCache.ordered``); per-step per-tier byte counts
+feed the perfmodel, from which the benchmarks reproduce the paper's
+p99/QPS curves (modeled, not measured).
 
 Placement is **per slot**: each batch slot carries its own page->device
 map, so a latency-SLO request can pin its pages fast (Fig. 7: any CXL
@@ -1042,19 +1042,41 @@ class TieredKVCache:
                                   page_device=jnp.asarray(out))
         return dataclasses.replace(self, prefix=blk)
 
-    def partitions(self, layer: int):
-        """[(k, v, valid)] per device pool for decode attention
-        (post-append); zero-width pools contribute no partial.  With a
-        shared-prefix pool attached, its referenced pages form one more
-        partition — merged exactly, like any other device split."""
+    def ordered(self, layer: int):
+        """``(k, v, valid)`` of one layer for decode attention
+        (post-append), every slot's rows in position order: ``(B,
+        n_pages * page_t, K, hd)``.  Each row is read from the device pool
+        that holds its page, or from the shared-prefix pool below the
+        slot's shared boundary.  Attention over this view reduces in one
+        order whatever the placement, so tiering never changes an answer
+        (a log-sum-exp merge of per-pool partials rounds differently for
+        each placement, and bf16 matmuls make that visible in tokens)."""
         upto = self.lengths[:, None] + 1
-        parts = [(self.k_parts[d][layer], self.v_parts[d][layer],
-                  self.pos_parts[d] < upto)
-                 for d in range(len(self.k_parts))
-                 if self.k_parts[d].shape[2]]
+        T = self.page_device.shape[1] * self.page_t
+        ks, vs, pos = [], [], []
+        for d in range(len(self.k_parts)):
+            if self.k_parts[d].shape[2]:
+                ks.append(self.k_parts[d][layer])
+                vs.append(self.v_parts[d][layer])
+                pos.append(jnp.where(self.pos_parts[d] < upto,
+                                     self.pos_parts[d], T))
         if self.prefix is not None and self.prefix.pool_pages:
-            parts.append(self.prefix.partition(layer))
-        return parts
+            k, v, valid = self.prefix.partition(layer)
+            ks.append(k)
+            vs.append(v)
+            pos.append(jnp.where(valid, jnp.arange(valid.shape[1]), T))
+        pos = jnp.concatenate(pos, axis=1)
+        B, R = pos.shape
+        bidx = jnp.arange(B)[:, None]
+        src = jnp.zeros((B, T), jnp.int32).at[bidx, pos].set(
+            jnp.arange(R, dtype=jnp.int32), mode="drop")
+        valid = jnp.zeros((B, T), bool).at[bidx, pos].set(True, mode="drop")
+
+        def gather(parts):
+            return jnp.take_along_axis(jnp.concatenate(parts, axis=1),
+                                       src[:, :, None, None], axis=1)
+
+        return gather(ks), gather(vs), valid
 
 
 def tiered_decode_step(cfg: ArchConfig, params: dict, cache: TieredKVCache,
@@ -1082,9 +1104,8 @@ def tiered_decode_step(cfg: ArchConfig, params: dict, cache: TieredKVCache,
             q = apply_rope(q[:, None], pos[:, None], cfg.rope_theta, cfg.rope_pct)[:, 0]
             k = apply_rope(k[:, None], pos[:, None], cfg.rope_theta, cfg.rope_pct)[:, 0]
         cache = cache.append_layer(li, k, v)
-        parts = [attn.attend_partial(q, kk, vv, valid)
-                 for (kk, vv, valid) in cache.partitions(li)]
-        o = attn.merge_partials(parts).astype(x.dtype)
+        o = attn.merge_partials([attn.attend_partial(q, *cache.ordered(li))]
+                                ).astype(x.dtype)
         x = x + o.reshape(B, H * hd) @ lp["attn"]["wo"]
         h = apply_norm(x[:, None], lp["ln2"], cfg.norm)[:, 0]
         x = x + mlp_apply(h, lp["mlp"], cfg.act)

@@ -21,9 +21,8 @@ attend those pages *by reference*:
 Sharing is exact: K rows were written with rope applied at absolute
 positions, and a shared prefix occupies the same absolute positions in
 every referencing request, so the cached rows are valid verbatim.  The
-pool contributes one extra attention partition per decode step, merged
-with the per-device partials through the same log-sum-exp combine —
-no attention math changes.
+pool serves the positions below a slot's shared boundary in the decode
+step's position-ordered KV view — no attention math changes.
 
 Divergence *inside* a page is copy-on-write: the matched head of the
 page is copied into the diverging request's own tier-placed pages (its
@@ -100,11 +99,9 @@ class PrefixBlock:
         return 2 * L * pt * K * hd * self.k.dtype.itemsize
 
     def partition(self, layer: int):
-        """(k, v, valid) attention partial over the referenced pool pages
-        — one extra partition per decode step, exactly merged with the
-        per-device partials.  Slots with no references contribute an
-        all-invalid row, which the finite-NEG_INF merge weights to zero.
-        """
+        """(k, v, valid) over the referenced pool pages of every slot,
+        ``(B, P_max * page_t, K, hd)``: row ``j`` holds position ``j``.
+        Slots with no references contribute an all-invalid row."""
         R = self.k.shape[1]
         pt = self.page_t
         B, Pm = self.slot_pages.shape

@@ -162,13 +162,26 @@ def test_donation_deletes_parent_buffers():
 def test_backend_resolution():
     assert resolve_backend("modeled") == "modeled"
     assert resolve_backend("staged") == "staged"
-    # auto falls back to modeled when the platform lacks pinned_host
+    # auto picks modeled where there is no pinned_host tier to place into
     expected = "memory_kind" if supports_memory_kinds() else "modeled"
     assert resolve_backend("auto") == expected
-    assert resolve_backend("memory_kind") == expected
     with pytest.raises(ValueError):
         resolve_backend("nope")
     assert set(BACKENDS) == {"modeled", "staged", "memory_kind"}
+
+
+def test_memory_kind_backend_raises_on_cpu():
+    """An explicit memory_kind backend is never quietly modeled: on the
+    CPU, whose device memory already is host memory, it raises."""
+    assert jax.devices()[0].platform == "cpu"
+    assert not supports_memory_kinds()
+    with pytest.raises(ValueError, match="pinned_host"):
+        resolve_backend("memory_kind")
+    with pytest.raises(ValueError, match="pinned_host"):
+        InterleavedTensor.from_array(jnp.zeros((64, FEAT)),
+                                     MemPolicy.from_slow_fraction(
+                                         "fast", "slow", 0.5),
+                                     page_rows=8, backend="memory_kind")
 
 
 def test_staged_backend_equivalence():

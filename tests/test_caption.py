@@ -295,7 +295,8 @@ def test_tiered_adamw_repartition_partial_delta():
 
 # -- KV cache repartition ------------------------------------------------------
 def test_kv_cache_repartition_preserves_decode(key):
-    """Attention partitions are invariant under re-tiering mid-sequence."""
+    """Decode logits are bit-identical under re-tiering mid-sequence:
+    attention reads the KV in position order whatever the placement."""
     from repro.models import registry
     from repro.serving.kv_cache import TieredKVCache, tiered_decode_step
     arch = registry.get("internvl2-2b").tiny()
@@ -314,4 +315,4 @@ def test_kv_cache_repartition_preserves_decode(key):
         outs_a.append(np.asarray(la))
         outs_b.append(np.asarray(lb))
     for a, b in zip(outs_a, outs_b):
-        np.testing.assert_allclose(a, b, atol=1e-4)
+        np.testing.assert_array_equal(a, b)

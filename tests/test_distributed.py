@@ -105,6 +105,17 @@ def test_dryrun_machinery_small_mesh():
     """, devices=8)
 
 
+def test_dryrun_import_leaves_xla_flags(monkeypatch):
+    """Only ``dryrun.main`` asks for 512 virtual devices: importing the
+    module must not reshape the backend of the process that imports it."""
+    import importlib
+
+    from repro.launch import dryrun
+    monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=nowhere")
+    importlib.reload(dryrun)
+    assert os.environ["XLA_FLAGS"] == "--xla_dump_to=nowhere"
+
+
 def test_collective_permute_and_groups_decode():
     """HLO analyzer's replica-group decoding on iota formats."""
     from repro.launch.hlo_analysis import decode_replica_groups

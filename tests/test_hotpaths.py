@@ -316,7 +316,7 @@ def test_kv_decode_traces_once_across_walk(key):
 
 
 def test_kv_decode_equivalence_with_headroom(key):
-    """Headroom-padded caches decode identically to exact-size caches
+    """Headroom-padded caches decode bit-identically to exact-size caches
     under a mid-sequence retile."""
     from repro.models import registry
     from repro.serving.kv_cache import tiered_decode_step
@@ -330,8 +330,7 @@ def test_kv_decode_equivalence_with_headroom(key):
     for t in range(6):
         la, a = tiered_decode_step(cfg, params, a, toks)
         lb, b = tiered_decode_step(cfg, params, b, toks)
-        np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
-                                   atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
         if t == 2:
             a = a.repartition_fraction(0.5, telemetry=Telemetry())
             b = b.repartition_fraction(0.5, telemetry=Telemetry())

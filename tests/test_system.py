@@ -98,6 +98,14 @@ def test_tiered_optimizer_training_equivalence():
                                    np.asarray(b, np.float32), atol=5e-5)
 
 
+@pytest.fixture
+def cache_dir_from_env(monkeypatch, tmp_path):
+    """main() turns on the persistent compile cache; with the variable set
+    it leaves JAX's configuration, and this worker, as they are."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+
+@pytest.mark.usefixtures("cache_dir_from_env")
 def test_train_driver_main_runs():
     from repro.launch import train as train_mod
     losses = train_mod.main([
@@ -108,6 +116,7 @@ def test_train_driver_main_runs():
     assert len(losses) == 12 and np.isfinite(losses).all()
 
 
+@pytest.mark.usefixtures("cache_dir_from_env")
 def test_serve_driver_main_runs():
     from repro.launch import serve as serve_mod
     done = serve_mod.main([
