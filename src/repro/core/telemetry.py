@@ -9,14 +9,22 @@ benchmarks and the planner's feedback loop see real traffic, and so a
 controller (§7) reads: it closes fixed observation windows over the
 cumulative route counters and reports per-epoch deltas plus EWMA
 bandwidths, writer concurrency, and fast-tier pressure gauges.
+
+:class:`HostReads` counts what does cross from the device to the host:
+the serving cache's device arrays copied out by numpy, by call site.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import threading
 import time
+import weakref
 from collections import defaultdict
 from typing import Optional
+
+import numpy as np
 
 
 @dataclasses.dataclass
@@ -113,6 +121,46 @@ class Telemetry:
 
 
 GLOBAL_TELEMETRY = Telemetry()
+
+_OPEN_HOST_READS: contextvars.ContextVar = contextvars.ContextVar(
+    "host_reads", default=None)
+
+
+class HostReads:
+    """Device arrays copied to the host, counted by call site into
+    ``host_read_bytes|<site>`` and ``host_reads|<site>`` of a
+    :class:`Telemetry`.  JAX keeps an array's host copy for as long as
+    the array lives, so each array counts once, however often it is read.
+
+    Inside ``with reads.open():`` every :func:`host_array` counts here."""
+
+    def __init__(self, telemetry: Telemetry):
+        self.telemetry = telemetry
+        self._seen: weakref.WeakValueDictionary = \
+            weakref.WeakValueDictionary()
+
+    @contextlib.contextmanager
+    def open(self):
+        token = _OPEN_HOST_READS.set(self)
+        try:
+            yield self
+        finally:
+            _OPEN_HOST_READS.reset(token)
+
+    def read(self, arr, site: str) -> np.ndarray:
+        if not isinstance(arr, np.ndarray) \
+                and self._seen.get(id(arr)) is not arr:
+            self._seen[id(arr)] = arr
+            self.telemetry.bump(f"host_read_bytes|{site}", arr.nbytes)
+            self.telemetry.bump(f"host_reads|{site}")
+        return np.asarray(arr)
+
+
+def host_array(arr, site: str) -> np.ndarray:
+    """``np.asarray(arr)``, counted under ``site`` by the open
+    :class:`HostReads`, if one is open."""
+    reads = _OPEN_HOST_READS.get()
+    return np.asarray(arr) if reads is None else reads.read(arr, site)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -243,14 +291,3 @@ class EpochWindow:
         self._gauges = {}
         return sample
 
-
-class Timer:
-    """Context-manager wall timer (blocks on jax arrays if passed)."""
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self.t0
-        return False

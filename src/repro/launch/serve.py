@@ -3,10 +3,15 @@
 Usage (CPU demo):
   python -m repro.launch.serve --arch qwen2.5-32b --tiny --requests 16 \
       --slow-fraction 0.5
+
+``--trace-dir DIR`` records the served run in a profiler trace under
+``DIR`` (open it in TensorBoard or Perfetto): the engine's ``engine.*``
+host spans beside the device's operations.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -22,6 +27,9 @@ from repro.core.warmstart import WarmStartMemo
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get as get_arch
 from repro.serving.engine import ServingEngine, kv_access_profile
+
+#: where the engine copies KV-cache device arrays to the host.
+HOST_READ_SITES = ("promote", "cow", "retile", "page_map")
 
 
 def main(argv=None, params=None):
@@ -71,6 +79,9 @@ def main(argv=None, params=None):
     ap.add_argument("--duels", type=int, default=0,
                     help="paired probe duels per Caption candidate point "
                          "(noise-robust probing); 0 = single-sample")
+    ap.add_argument("--trace-dir", default=None,
+                    help="record the served run in a profiler trace "
+                         "under this directory")
     ap.add_argument("--ledger-report", action="store_true",
                     help="register the serving pools (KV + shared-prefix "
                          "pages) in a TierLedger and print the per-tier "
@@ -137,23 +148,39 @@ def main(argv=None, params=None):
         slo = ("latency" if args.latency_every
                and i % args.latency_every == 0 else "batch")
         engine.submit(prompt, max_new_tokens=args.new_tokens, slo=slo)
-    done = engine.run_until_drained()
+    counters0 = dict(engine.telemetry.counters)
+    traced = (jax.profiler.trace(args.trace_dir) if args.trace_dir
+              else contextlib.nullcontext())
+    with traced:
+        done = engine.run_until_drained()
     wall = time.perf_counter() - t0
     if mover is not None:
         mover.close()
+    counters = {k: v - counters0.get(k, 0.0)
+                for k, v in engine.telemetry.counters.items()}
     lats = sorted(r.latency for r in done)
-    modeled = sorted(r.modeled_seconds for r in done)
+    queue = sorted(r.admitted_at - r.submitted_at for r in done)
+    ttft = sorted(r.first_token_at - r.submitted_at for r in done)
     p99 = lats[int(len(lats) * 0.99) - 1] if len(lats) > 1 else lats[0]
     print(f"completed={len(done)} wall={wall:.2f}s "
           f"p50={lats[len(lats)//2]*1e3:.1f}ms p99={p99*1e3:.1f}ms "
-          f"modeled_p50={modeled[len(modeled)//2]*1e3:.3f}ms "
+          f"queue_p50={queue[len(queue)//2]*1e3:.1f}ms "
+          f"ttft_p50={ttft[len(ttft)//2]*1e3:.1f}ms "
           f"slow_frac={engine.cache.slow_fraction():.2f}")
+    print("host reads: " + " ".join(
+        f"{site}={int(counters.get(f'host_read_bytes|{site}', 0))}B"
+        f"/{int(counters.get(f'host_reads|{site}', 0))}"
+        for site in HOST_READ_SITES)
+        + f" promoted_pages={int(counters.get('prefix.promoted_pages', 0))}")
     if topology.n_slow > 1:
         fr = engine.cache.device_fractions()
         print("devices: " + " ".join(f"{k}={v:.2f}" for k, v in fr.items()))
     if caption is not None:
         traj = " -> ".join(f"{f:.2f}" for _, f in engine.caption_trace[-8:])
-        print(f"caption: phase={caption.phase.value} trajectory {traj}")
+        print(f"caption: phase={caption.phase.value} "
+              f"epochs={int(counters.get('caption.epochs', 0))} "
+              f"actuations={int(counters.get('caption.actuations', 0))} "
+              f"trajectory {traj}")
     if memo is not None:
         memo.save(args.memo_path)
         print(f"warmstart: entries={len(memo)} hits={memo.hits} "

@@ -6,6 +6,15 @@ Per-request latency is tracked both as measured wall time and as
 *modeled* time on the target tier topology (compute + per-tier KV
 streaming via the calibrated perfmodel), which is what the Redis-
 analogue benchmark (Figs. 6-7) reports.
+
+The engine's host work is traced on the profiler's clock, in spans named
+``engine.<layer>.<part>`` (``engine.prefix.attach``/``promote``,
+``engine.caption.observe``/``actuate``, ``engine.step.inputs``/
+``model_time``/``sample``), so a device trace shows what the host did in
+each of the device's idle gaps.  The KV-cache device arrays a step copies
+to the host are counted in ``host_read_bytes|<site>`` and
+``host_reads|<site>`` of the engine's telemetry
+(:class:`~repro.core.telemetry.HostReads`).
 """
 from __future__ import annotations
 
@@ -22,7 +31,8 @@ from repro.core import perfmodel
 from repro.core.caption import CaptionController
 from repro.core.classifier import AccessProfile
 from repro.core.policy import MemPolicy
-from repro.core.telemetry import GLOBAL_TELEMETRY, EpochWindow
+from repro.core.telemetry import (GLOBAL_TELEMETRY, EpochWindow, HostReads,
+                                  host_array)
 from repro.core.tiers import OpClass, TierTopology
 from repro.serving.kv_cache import TieredKVCache, tiered_decode_step
 from repro.serving.prefix_cache import PrefixCache
@@ -38,6 +48,7 @@ class Request:
     #: leave the Caption repartition population); "batch" tolerate slow.
     slo: str = "batch"
     submitted_at: float = 0.0
+    admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     generated: list[int] = dataclasses.field(default_factory=list)
@@ -196,6 +207,7 @@ class ServingEngine:
             arbiter.register(buffer_name, caption)
         self.mover = mover
         self.telemetry = telemetry
+        self.host_reads = HostReads(telemetry)
         self._steps = 0
         self._epoch_tokens = 0
         self._epoch_modeled_s = 0.0
@@ -316,7 +328,8 @@ class ServingEngine:
                 # shared pool pages evacuate the dead device too — each
                 # page ships once (refcount-deduplicated), to fast
                 ord_ = self.cache.device_names.index(name)
-                pdev = np.asarray(self.cache.prefix.page_device)
+                pdev = host_array(self.cache.prefix.page_device,
+                                  "page_map")
                 if (pdev == ord_).any():
                     new = pdev.copy()
                     new[pdev == ord_] = 0
@@ -379,6 +392,7 @@ class ServingEngine:
                     # (ordering preserved; starvation-bounded).
                     break
                 req = self.queue.pop(0)
+                req.admitted_at = time.perf_counter()
                 self._defer_steps = 0
                 self.slots[i] = req
                 # Latency-SLO admission: pin the slot's pages fast before
@@ -410,55 +424,61 @@ class ServingEngine:
         pages into slot ``i`` by reference and copy-on-write the head of
         a partially-matched page into the slot's own tier.  Returns the
         number of prompt tokens the replay loop can skip."""
-        idx = self.prefix_index
-        nodes, partial, plen = idx.match(req.prompt)
-        Pm = self.cache.prefix.slot_pages.shape[1]
-        nodes = nodes[:Pm]
-        full_rows = len(nodes) * self.cache.page_t
-        if nodes:
-            idx.acquire(nodes)
-            self._slot_refs[i] = nodes
-            self.cache = self.cache.attach_prefix(
-                i, [n.page for n in nodes])
-        if partial is not None and plen > 0:
-            # Copy-on-write at the divergence point: the writer gets a
-            # PRIVATE copy of the matched head in its own tier-placed
-            # pages; the shared page stays immutable for its readers.
-            idx.touch(partial)
-            idx.cow_copies += 1
-            blk = self.cache.prefix
-            k_rows = np.asarray(blk.k)[:, partial.page, :plen]
-            v_rows = np.asarray(blk.v)[:, partial.page, :plen]
-            self.cache = self.cache.write_token_rows(
-                i, full_rows, k_rows, v_rows)
-            src_ord = int(np.asarray(blk.page_device)[partial.page])
-            dst_ord = int(self.cache._host_dev()[i][full_rows
-                                                    // self.cache.page_t])
-            names = self._device_names
-            if src_ord != dst_ord and max(src_ord, dst_ord) < len(names):
-                row_b = (self.cache._page_kv_bytes()
-                         * plen // self.cache.page_t)
-                self.telemetry.record_move(
-                    names[src_ord], names[dst_ord], row_b, 0.0,
-                    source=self.buffer_name)
-        return full_rows + plen
+        with jax.profiler.TraceAnnotation("engine.prefix.attach",
+                                          rid=req.rid):
+            idx = self.prefix_index
+            nodes, partial, plen = idx.match(req.prompt)
+            Pm = self.cache.prefix.slot_pages.shape[1]
+            nodes = nodes[:Pm]
+            full_rows = len(nodes) * self.cache.page_t
+            if nodes:
+                idx.acquire(nodes)
+                self._slot_refs[i] = nodes
+                self.cache = self.cache.attach_prefix(
+                    i, [n.page for n in nodes])
+            if partial is not None and plen > 0:
+                # Copy-on-write at the divergence point: the writer gets a
+                # PRIVATE copy of the matched head in its own tier-placed
+                # pages; the shared page stays immutable for its readers.
+                idx.touch(partial)
+                idx.cow_copies += 1
+                blk = self.cache.prefix
+                k_rows = host_array(blk.k, "cow")[:, partial.page, :plen]
+                v_rows = host_array(blk.v, "cow")[:, partial.page, :plen]
+                self.cache = self.cache.write_token_rows(
+                    i, full_rows, k_rows, v_rows)
+                src_ord = int(host_array(blk.page_device,
+                                         "page_map")[partial.page])
+                dst_ord = int(self.cache._host_dev()[i][full_rows
+                                                        // self.cache.page_t])
+                names = self._device_names
+                if src_ord != dst_ord and max(src_ord, dst_ord) < len(names):
+                    row_b = (self.cache._page_kv_bytes()
+                             * plen // self.cache.page_t)
+                    self.telemetry.record_move(
+                        names[src_ord], names[dst_ord], row_b, 0.0,
+                        source=self.buffer_name)
+            return full_rows + plen
 
     def _promote_prefix(self, i: int, req: Request) -> None:
         """After prefill, publish the prompt's novel full pages into the
         shared pool so the NEXT request with this prefix shares them."""
-        placed = self.prefix_index.insert(req.prompt,
-                                          self._slot_refs.get(i, []))
-        if not placed:
-            return
-        pt = self.cache.page_t
-        ks, vs = [], []
-        for pno, _node in placed:
-            k_pg, v_pg = self.cache.gather_token_rows(i, pno * pt, pt)
-            ks.append(k_pg)
-            vs.append(v_pg)
-        self.cache = self.cache.write_prefix_pages(
-            [n.page for _, n in placed],
-            np.stack(ks, axis=1), np.stack(vs, axis=1), device=0)
+        with jax.profiler.TraceAnnotation("engine.prefix.promote",
+                                          rid=req.rid):
+            placed = self.prefix_index.insert(req.prompt,
+                                              self._slot_refs.get(i, []))
+            if not placed:
+                return
+            self.telemetry.bump("prefix.promoted_pages", len(placed))
+            pt = self.cache.page_t
+            ks, vs = [], []
+            for pno, _node in placed:
+                k_pg, v_pg = self.cache.gather_token_rows(i, pno * pt, pt)
+                ks.append(k_pg)
+                vs.append(v_pg)
+            self.cache = self.cache.write_prefix_pages(
+                [n.page for _, n in placed],
+                np.stack(ks, axis=1), np.stack(vs, axis=1), device=0)
 
     # -- cost-model admission ---------------------------------------------------
     def _admission_ok(self, req: Request) -> bool:
@@ -481,7 +501,7 @@ class ServingEngine:
                       if r is not None and j not in self.pinned_slots) + 1
         pfx_fast = 0
         if self.cache.prefix is not None:
-            pdev = np.asarray(self.cache.prefix.page_device)
+            pdev = host_array(self.cache.prefix.page_device, "page_map")
             pfx_fast = (int((pdev == 0).sum())
                         * self.cache._page_kv_bytes())
         predicted = (n_lat * slot_bytes
@@ -568,14 +588,32 @@ class ServingEngine:
 
     def step(self) -> int:
         """Decode one token for all active slots. Returns #active."""
-        self._admit()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return 0
-        logits, self.cache = self._decode(
-            self.params, self.cache, self._current_tokens())
-        self.last_logits = logits
-        step_model_s = self.modeled_step_seconds()
+        with self.host_reads.open():
+            self._admit()
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            if not active:
+                return 0
+            with jax.profiler.TraceAnnotation("engine.step.inputs"):
+                tokens = self._current_tokens()
+            logits, self.cache = self._decode(self.params, self.cache, tokens)
+            self.last_logits = logits
+            with jax.profiler.TraceAnnotation("engine.step.model_time"):
+                step_model_s = self.modeled_step_seconds()
+            with jax.profiler.TraceAnnotation("engine.step.sample"):
+                self._sample(active, logits, step_model_s)
+            self._steps += 1
+            self._epoch_tokens += len(active)
+            self._epoch_modeled_s += step_model_s
+            if self._inflight_move_bytes:
+                self._inflight_compute_s += step_model_s
+            if (self.caption is not None
+                    and self._steps % self.caption.cfg.epoch_steps == 0):
+                self._caption_epoch()
+            return len(active)
+
+    def _sample(self, active: list[int], logits, step_model_s: float) -> None:
+        """Greedy tokens for the ``active`` slots; finished requests
+        leave their slots."""
         now = time.perf_counter()
         toks = sample_greedy(logits)
         for i in active:
@@ -596,15 +634,6 @@ class ServingEngine:
                 self._reset_slot(i)
                 # slot rejoins the batch-class repartition population
                 self.pinned_slots.discard(i)
-        self._steps += 1
-        self._epoch_tokens += len(active)
-        self._epoch_modeled_s += step_model_s
-        if self._inflight_move_bytes:
-            self._inflight_compute_s += step_model_s
-        if (self.caption is not None
-                and self._steps % self.caption.cfg.epoch_steps == 0):
-            self._caption_epoch()
-        return len(active)
 
     # -- async migration/compute overlap (ISSUE 8) ----------------------------
     def _modeled_move_seconds(self, nbytes: int) -> float:
@@ -663,7 +692,7 @@ class ServingEngine:
             return
         if len(self.cache.device_names) < 2:
             return
-        pdev = np.asarray(blk.page_device)
+        pdev = host_array(blk.page_device, "page_map")
         alloc = np.nonzero(pdev >= 0)[0]
         if alloc.size == 0:
             return
@@ -679,6 +708,20 @@ class ServingEngine:
 
     # -- Caption control loop (§7): sample -> decide -> re-tier ---------------
     def _caption_epoch(self) -> None:
+        self.telemetry.bump("caption.epochs")
+        before = self.caption.fraction
+        with jax.profiler.TraceAnnotation("engine.caption.observe"):
+            decision = self._caption_observe()
+        if abs(decision.fraction - before) > 1e-9 or (
+                len(self._device_names) > 2 and decision.changed):
+            self.telemetry.bump("caption.actuations")
+            with jax.profiler.TraceAnnotation("engine.caption.actuate"):
+                self._caption_actuate(decision)
+        self.caption_trace.append((self._steps, self.caption.fraction))
+
+    def _caption_observe(self):
+        """Bill the epoch's modeled KV traffic and close the observation
+        window: the controller's (or the arbiter's) decision."""
         # Previous epoch's unfenced migrations ran under this epoch's
         # decode steps — drain them before issuing new movement.
         self._drain_migrations()
@@ -736,7 +779,6 @@ class ServingEngine:
             kv_fast_bytes = (self.cache.k_fast.size + self.cache.v_fast.size) * item
             pressure = min(kv_fast_bytes / self.topology.fast.capacity_bytes,
                            1.0)
-        before = self.caption.fraction
         tput = self._epoch_tokens / dt
         slo_names = (tuple(self._device_names[1:]) if multi
                      else self._slow_name)
@@ -750,50 +792,54 @@ class ServingEngine:
                 fast_pressure=pressure, slow_name=slo_names, seconds=dt)
         self._epoch_tokens = 0
         self._epoch_modeled_s = 0.0
-        if abs(decision.fraction - before) > 1e-9 or (
-                multi and decision.changed):
-            active = self._active_slow_names()
-            b0 = self.mover.bytes_submitted if self.mover is not None else 0
-            t0 = time.perf_counter()
-            wait = not self.overlap
-            if multi and (len(decision.weights) > 1
-                          or (active and active[0] in self._device_names)):
-                # Expand the controller's live-device weight vector onto
-                # the cache's (union) device ordinals by name — after an
-                # elastic remove the two differ, and a removed device
-                # must actuate to exactly zero.
-                self.cache = self.cache.repartition_weights(
-                    self._expand_weights(decision.weights),
-                    pinned_slots=self.pinned_slots,
-                    mover=self.mover, telemetry=self.telemetry,
-                    policy_names=self._device_names, source=src,
-                    donate=self.donate_kv, wait=wait)
+        return decision
+
+    def _caption_actuate(self, decision) -> None:
+        """Re-tier the KV pages, and the shared prefix pages, to the
+        decision; then hand the controller the operating point reached."""
+        multi = len(self._device_names) > 2
+        src = self.buffer_name
+        active = self._active_slow_names()
+        b0 = self.mover.bytes_submitted if self.mover is not None else 0
+        t0 = time.perf_counter()
+        wait = not self.overlap
+        if multi and (len(decision.weights) > 1
+                      or (active and active[0] in self._device_names)):
+            # Expand the controller's live-device weight vector onto
+            # the cache's (union) device ordinals by name — after an
+            # elastic remove the two differ, and a removed device
+            # must actuate to exactly zero.
+            self.cache = self.cache.repartition_weights(
+                self._expand_weights(decision.weights),
+                pinned_slots=self.pinned_slots,
+                mover=self.mover, telemetry=self.telemetry,
+                policy_names=self._device_names, source=src,
+                donate=self.donate_kv, wait=wait)
+        else:
+            self.cache = self.cache.repartition_fraction(
+                decision.fraction, pinned_slots=self.pinned_slots,
+                mover=self.mover,
+                telemetry=self.telemetry, fast_tier=self._fast_name,
+                slow_tier=self._slow_name, source=src,
+                donate=self.donate_kv, wait=wait)
+        if self.cache.prefix is not None:
+            self._retier_prefix(decision.fraction, wait=wait)
+        moved = ((self.mover.bytes_submitted - b0)
+                 if self.mover is not None else 0)
+        self._account_actuation(moved, time.perf_counter() - t0)
+        self.register_pools()
+        # Page rounding may achieve less (or none) of the request: the
+        # controller must continue from the real operating point.  With
+        # zero tunable slots (everything SLO-pinned) there IS no
+        # operating point to read back — feeding 0.0 would corrupt the
+        # walk, so the decision stands until slots unpin.
+        if len(self.pinned_slots) < self.max_batch:
+            if multi and self.caption.n_slow > 1:
+                self.caption.actuated_weights(self._project_weights(
+                    self.cache.weights(self.pinned_slots)))
             else:
-                self.cache = self.cache.repartition_fraction(
-                    decision.fraction, pinned_slots=self.pinned_slots,
-                    mover=self.mover,
-                    telemetry=self.telemetry, fast_tier=self._fast_name,
-                    slow_tier=self._slow_name, source=src,
-                    donate=self.donate_kv, wait=wait)
-            if self.cache.prefix is not None:
-                self._retier_prefix(decision.fraction, wait=wait)
-            moved = ((self.mover.bytes_submitted - b0)
-                     if self.mover is not None else 0)
-            self._account_actuation(moved, time.perf_counter() - t0)
-            self.register_pools()
-            # Page rounding may achieve less (or none) of the request: the
-            # controller must continue from the real operating point.  With
-            # zero tunable slots (everything SLO-pinned) there IS no
-            # operating point to read back — feeding 0.0 would corrupt the
-            # walk, so the decision stands until slots unpin.
-            if n_unpinned > 0:
-                if multi and self.caption.n_slow > 1:
-                    self.caption.actuated_weights(self._project_weights(
-                        self.cache.weights(self.pinned_slots)))
-                else:
-                    self.caption.actuated(
-                        self.cache.slow_fraction(self.pinned_slots))
-        self.caption_trace.append((self._steps, self.caption.fraction))
+                self.caption.actuated(
+                    self.cache.slow_fraction(self.pinned_slots))
 
     def run_until_drained(self, max_steps: int = 10_000) -> list[Request]:
         steps = 0

@@ -46,7 +46,7 @@ from repro.core.interleave import (_policy_device_map, minimal_delta_weights,
                                    resolve_device_names, route_pure_runs)
 from repro.core.mover import LANE_BULK, LANE_LATENCY
 from repro.core.policy import MemPolicy
-from repro.core.telemetry import GLOBAL_TELEMETRY
+from repro.core.telemetry import GLOBAL_TELEMETRY, host_array
 from repro.models import attention as attn
 from repro.models.common import apply_norm, dtype_of, mlp_apply
 from repro.serving.prefix_cache import NO_PAGE, UNALLOCATED, PrefixBlock
@@ -228,7 +228,7 @@ class TieredKVCache:
         the device array each time."""
         cached = self.__dict__.get("_host_cache")
         if cached is None:
-            cached = np.asarray(self.page_device)
+            cached = host_array(self.page_device, "page_map")
             self.__dict__["_host_cache"] = cached
         return cached
 
@@ -363,10 +363,11 @@ class TieredKVCache:
         L = self.k_parts[0].shape[0]
         K, hd = self.k_parts[0].shape[3:]
         out = {}
-        pfx_dev = (np.asarray(self.prefix.page_device)
+        pfx_dev = (host_array(self.prefix.page_device, "page_map")
                    if self.prefix is not None else None)
         for i, name in enumerate(self.device_names):
-            rows = int((np.asarray(self.pos_parts[i]) != _INT32_MAX).sum())
+            rows = int((host_array(self.pos_parts[i], "page_map")
+                        != _INT32_MAX).sum())
             out[name] = 2 * L * rows * K * hd * item
             if pfx_dev is not None:
                 # shared pool pages occupy storage ONCE, however many
@@ -392,7 +393,7 @@ class TieredKVCache:
                 * self.k_parts[i].dtype.itemsize
                 + self.pos_parts[i].size * self.pos_parts[i].dtype.itemsize)
         if self.prefix is not None:
-            pdev = np.asarray(self.prefix.page_device)
+            pdev = host_array(self.prefix.page_device, "page_map")
             pb = self.prefix.page_bytes()
             for i, name in enumerate(self.device_names):
                 out[name] += int((pdev == i).sum()) * pb
@@ -439,8 +440,8 @@ class TieredKVCache:
         reader streams it each decode step)."""
         if self.prefix is None:
             return {}
-        sp = np.asarray(self.prefix.slot_pages)
-        pdev = np.asarray(self.prefix.page_device)
+        sp = host_array(self.prefix.slot_pages, "page_map")
+        pdev = host_array(self.prefix.page_device, "page_map")
         refs = sp[sp >= 0]
         if refs.size == 0:
             return {}
@@ -608,8 +609,8 @@ class TieredKVCache:
         any pool is written, so payloads slice pristine source data."""
         pt = self.page_t
         page_kv_bytes = self._page_kv_bytes()
-        k_np = [np.asarray(kp) for kp in self.k_parts]
-        v_np = [np.asarray(vp) for vp in self.v_parts]
+        k_np = [host_array(kp, "retile") for kp in self.k_parts]
+        v_np = [host_array(vp, "retile") for vp in self.v_parts]
         descs = []
         for slots in groups.values():
             b0, sl = slots[0], np.asarray(slots)
@@ -664,7 +665,7 @@ class TieredKVCache:
                         len(policy_names or ()), n_old)
         route = self._route_names(n_devices, policy_names, fast_tier,
                                   slow_tier)
-        old_local = np.asarray(self.page_local)
+        old_local = host_array(self.page_local, "page_map")
         groups = self._slot_groups(old_dev, new_dev, old_local)
         # Bill / ship the movement FIRST (payloads slice the CURRENT
         # pools — required for the donated in-place path too).
@@ -722,9 +723,10 @@ class TieredKVCache:
         caps = self.capacity_pages()
         n_devices = len(self.k_parts)
         new_local = old_local.copy()
-        k_np = [np.asarray(kp) for kp in self.k_parts]   # pristine views
-        v_np = [np.asarray(vp) for vp in self.v_parts]
-        pos_np = [np.asarray(p).copy() for p in self.pos_parts]
+        # pristine views
+        k_np = [host_array(kp, "retile") for kp in self.k_parts]
+        v_np = [host_array(vp, "retile") for vp in self.v_parts]
+        pos_np = [host_array(p, "page_map").copy() for p in self.pos_parts]
         plan = []  # (dst_dev, slot ids, dst rows, k slab, v slab)
         for slots in groups.values():
             b0, sl = slots[0], np.asarray(slots)
@@ -785,8 +787,8 @@ class TieredKVCache:
                 continue
             if d not in writable_k:
                 FULL_SHARD_COPIES.bump(2)  # one full CoW per K and V pool
-                writable_k[d] = np.asarray(k_pools[d]).copy()
-                writable_v[d] = np.asarray(v_pools[d]).copy()
+                writable_k[d] = host_array(k_pools[d], "retile").copy()
+                writable_v[d] = host_array(v_pools[d], "retile").copy()
             writable_k[d][np.ix_(L_idx, sl, dst_rows)] = k_slab
             writable_v[d][np.ix_(L_idx, sl, dst_rows)] = v_slab
         for d in writable_k:
@@ -829,8 +831,8 @@ class TieredKVCache:
         v_new = [np.zeros((L, B, caps[d] * pt, K, hd), dt)
                  for d in range(n_devices)]
         FULL_SHARD_COPIES.bump(2 * n_devices)
-        k_np = [np.asarray(kp) for kp in self.k_parts]
-        v_np = [np.asarray(vp) for vp in self.v_parts]
+        k_np = [host_array(kp, "retile") for kp in self.k_parts]
+        v_np = [host_array(vp, "retile") for vp in self.v_parts]
         n_old = len(self.k_parts)
         for slots in groups.values():
             b0, sl = slots[0], np.asarray(slots)
@@ -919,7 +921,7 @@ class TieredKVCache:
         pt = self.page_t
         at = np.arange(pt)
         dev = self._host_dev()[i]
-        loc = np.asarray(self.page_local)[i]
+        loc = host_array(self.page_local, "page_map")[i]
         pos_new = list(self.pos_parts)
         for d in range(len(self.k_parts)):
             T_d = self.k_parts[d].shape[2]
@@ -938,7 +940,8 @@ class TieredKVCache:
         positions = np.arange(start, start + n)
         page = positions // self.page_t
         dev = self._host_dev()[i][page]
-        rows = (np.asarray(self.page_local)[i][page] * self.page_t
+        rows = (host_array(self.page_local, "page_map")[i][page]
+                * self.page_t
                 + positions % self.page_t)
         return dev, rows
 
@@ -954,8 +957,10 @@ class TieredKVCache:
         out_v = np.zeros_like(out_k)
         for d in np.unique(dev):
             sel = np.nonzero(dev == d)[0]
-            out_k[:, sel] = np.asarray(self.k_parts[d])[:, i, rows[sel]]
-            out_v[:, sel] = np.asarray(self.v_parts[d])[:, i, rows[sel]]
+            k_pool = host_array(self.k_parts[d], "promote")
+            v_pool = host_array(self.v_parts[d], "promote")
+            out_k[:, sel] = k_pool[:, i, rows[sel]]
+            out_v[:, sel] = v_pool[:, i, rows[sel]]
         return out_k, out_v
 
     def write_token_rows(self, i: int, start: int, k_rows,
@@ -1006,7 +1011,7 @@ class TieredKVCache:
         reference it — refcount-weighted (deduplicated) migration, vs
         the per-slot billing private pages pay in ``_retile``."""
         assert self.prefix is not None
-        old = np.asarray(self.prefix.page_device)
+        old = host_array(self.prefix.page_device, "page_map")
         new_dev = np.asarray(new_dev, np.int32)
         moved = np.nonzero((old >= 0) & (new_dev >= 0)
                            & (old != new_dev))[0]
@@ -1021,8 +1026,8 @@ class TieredKVCache:
                               []).append(int(pg))
         if mover is not None:
             from repro.core.mover import Descriptor
-            k_np = np.asarray(self.prefix.k)
-            v_np = np.asarray(self.prefix.v)
+            k_np = host_array(self.prefix.k, "retile")
+            v_np = host_array(self.prefix.v, "retile")
             descs = [Descriptor(route[d0], route[d1],
                                 (jnp.asarray(k_np[:, pages]),
                                  jnp.asarray(v_np[:, pages])),
