@@ -171,7 +171,9 @@ def main(argv=None, params=None):
         f"{site}={int(counters.get(f'host_read_bytes|{site}', 0))}B"
         f"/{int(counters.get(f'host_reads|{site}', 0))}"
         for site in HOST_READ_SITES)
-        + f" promoted_pages={int(counters.get('prefix.promoted_pages', 0))}")
+        + f" promoted_pages={int(counters.get('prefix.promoted_pages', 0))}"
+        + "".join(f" prefill_{name}={int(counters.get(f'prefill.{name}', 0))}"
+                  for name in ("calls", "tokens", "padded_tokens")))
     if topology.n_slow > 1:
         fr = engine.cache.device_fractions()
         print("devices: " + " ".join(f"{k}={v:.2f}" for k, v in fr.items()))

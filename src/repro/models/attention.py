@@ -212,6 +212,26 @@ def merge_partials(parts):
     return acc_t / jnp.maximum(l_t, 1e-30)[..., None]
 
 
+def attend_chunk(q, k, v, valid: jax.Array):
+    """Attention of a chunk of one sequence's queries over its KV view,
+    with the float32 accumulation of ``attend_partial`` and
+    ``merge_partials`` for a single partition.
+
+    q: (C,H,hd); k,v: (T,K,hd); valid: (C,T) bool.  Returns (C,H,hd)."""
+    C, H, hd = q.shape
+    K = k.shape[1]
+    G = H // K
+    qg = q.reshape(C, K, G, hd).astype(jnp.float32)
+    scores = jnp.einsum("ckgh,tkh->ckgt", qg,
+                        k.astype(jnp.float32)) / np.sqrt(hd)
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+    m = jnp.max(scores, axis=-1)
+    p = jnp.exp(scores - m[..., None])
+    l = jnp.sum(p, axis=-1)
+    acc = jnp.einsum("ckgt,tkh->ckgh", p, v.astype(jnp.float32))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(C, H, hd)
+
+
 def decode_attention(
     x_tok: jax.Array,  # (B, D) current token activations
     p: dict,

@@ -7,14 +7,20 @@ Per-request latency is tracked both as measured wall time and as
 streaming via the calibrated perfmodel), which is what the Redis-
 analogue benchmark (Figs. 6-7) reports.
 
+Admission writes a prompt's K/V into its slot by chunked prefill
+(``kv_cache.tiered_prefill_step``, ``PREFILL_CHUNK`` tokens a call of one
+jitted program), not one decode call per prompt token.
+
 The engine's host work is traced on the profiler's clock, in spans named
 ``engine.<layer>.<part>`` (``engine.prefix.attach``/``promote``,
-``engine.caption.observe``/``actuate``, ``engine.step.inputs``/
-``model_time``/``sample``), so a device trace shows what the host did in
-each of the device's idle gaps.  The KV-cache device arrays a step copies
-to the host are counted in ``host_read_bytes|<site>`` and
-``host_reads|<site>`` of the engine's telemetry
-(:class:`~repro.core.telemetry.HostReads`).
+``engine.prefill``, ``engine.caption.observe``/``actuate``,
+``engine.step.inputs``/``model_time``/``sample``), so a device trace
+shows what the host did in each of the device's idle gaps.  The KV-cache
+device arrays a step copies to the host are counted in
+``host_read_bytes|<site>`` and ``host_reads|<site>`` of the engine's
+telemetry (:class:`~repro.core.telemetry.HostReads`); the prefill's
+chunk calls in ``prefill.calls``, ``prefill.tokens`` and
+``prefill.padded_tokens``.
 """
 from __future__ import annotations
 
@@ -34,9 +40,14 @@ from repro.core.policy import MemPolicy
 from repro.core.telemetry import (GLOBAL_TELEMETRY, EpochWindow, HostReads,
                                   host_array)
 from repro.core.tiers import OpClass, TierTopology
-from repro.serving.kv_cache import TieredKVCache, tiered_decode_step
+from repro.serving.kv_cache import (TieredKVCache, tiered_decode_step,
+                                    tiered_prefill_step)
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampler import sample_greedy
+
+#: prompt tokens per call of the prefill program.  One fixed block, so the
+#: program traces once whatever the slot, its start or the prompt's length.
+PREFILL_CHUNK = 64
 
 
 @dataclasses.dataclass
@@ -184,6 +195,13 @@ class ServingEngine:
             return tiered_decode_step(cfg, p, c, t)
 
         self._decode = jax.jit(_decode_traced)
+        self.prefill_traces = 0
+
+        def _prefill_traced(p, c, slot, t, n):
+            self.prefill_traces += 1
+            return tiered_prefill_step(cfg, p, c, slot, t, n)
+
+        self._prefill = jax.jit(_prefill_traced)
         self.slots: list[Optional[Request]] = [None] * max_batch
         # Latency-SLO slots (request policy lives here, not in the cache):
         # excluded from Caption repartitions while their request is active.
@@ -405,14 +423,17 @@ class ServingEngine:
                     self.pinned_slots.add(i)
                 self._reset_slot(i)
                 # Shared-prefix fast path: attach the longest cached
-                # prefix by reference and replay ONLY the suffix — the
-                # decode-replay prefill (exact; slot-local) starts at
-                # the shared boundary instead of token zero.
+                # prefix by reference and prefill ONLY the suffix, from
+                # the shared boundary instead of token zero.  The last
+                # prompt token is left for the first decode step.
                 shared = 0
                 if self.prefix_index is not None:
                     shared = self._attach_prefix(i, req)
-                for tok in req.prompt[shared:-1]:
-                    self._step_slot_token(i, tok)
+                suffix = req.prompt[shared:-1]
+                if suffix:
+                    with jax.profiler.TraceAnnotation("engine.prefill",
+                                                      rid=req.rid):
+                        self._prefill_slot(i, suffix)
                 if self.prefix_index is not None:
                     self._promote_prefix(i, req)
                 self.prefill_tokens_total += max(len(req.prompt) - 1, 0)
@@ -542,6 +563,26 @@ class ServingEngine:
                 continue
             toks[i] = (req.generated[-1] if req.generated else req.prompt[-1])
         return jnp.asarray(toks)
+
+    def _prefill_slot(self, i: int, tokens: list[int]) -> None:
+        """Write ``tokens``' K/V into slot ``i`` from its current length,
+        ``PREFILL_CHUNK`` tokens a call of the prefill program."""
+        for start in range(0, len(tokens), PREFILL_CHUNK):
+            if start:
+                # Each call returns a whole new cache: a long prompt's
+                # calls dispatched at once would hold one cache each
+                # until the device drains them.  One in flight at a time.
+                jax.block_until_ready(self.cache.lengths)
+            chunk = tokens[start:start + PREFILL_CHUNK]
+            block = np.zeros((PREFILL_CHUNK,), np.int32)
+            block[:len(chunk)] = chunk
+            self.cache = self._prefill(self.params, self.cache, np.int32(i),
+                                       jnp.asarray(block),
+                                       np.int32(len(chunk)))
+            self.telemetry.bump("prefill.calls")
+            self.telemetry.bump("prefill.tokens", len(chunk))
+            self.telemetry.bump("prefill.padded_tokens",
+                                PREFILL_CHUNK - len(chunk))
 
     def _step_slot_token(self, i: int, token: int) -> None:
         toks = np.zeros((self.max_batch,), np.int32)

@@ -3,7 +3,9 @@
 The KV time axis is split into pages placed across (fast, slow...) devices
 by a MemPolicy — the paper's N:M weighted interleave applied to serving
 state.  Decode reads each page from the device pool that holds it, in
-position order (``TieredKVCache.ordered``); per-step per-tier byte counts
+position order (``TieredKVCache.ordered``); prefill writes a chunk of
+prompt tokens into one slot's pages and attends over that slot's view
+alone (``tiered_prefill_step``); per-step per-tier byte counts
 feed the perfmodel, from which the benchmarks reproduce the paper's
 p99/QPS curves (modeled, not measured).
 
@@ -48,7 +50,7 @@ from repro.core.mover import LANE_BULK, LANE_LATENCY
 from repro.core.policy import MemPolicy
 from repro.core.telemetry import GLOBAL_TELEMETRY, host_array
 from repro.models import attention as attn
-from repro.models.common import apply_norm, dtype_of, mlp_apply
+from repro.models.common import apply_norm, apply_rope, dtype_of, mlp_apply
 from repro.serving.prefix_cache import NO_PAGE, UNALLOCATED, PrefixBlock
 
 _INT32_MAX = np.iinfo(np.int32).max
@@ -471,6 +473,34 @@ class TieredKVCache:
             k_parts[d] = k_parts[d].at[layer, bidx, idx].set(
                 k_new.astype(k_parts[d].dtype), mode="drop")
             v_parts[d] = v_parts[d].at[layer, bidx, idx].set(
+                v_new.astype(v_parts[d].dtype), mode="drop")
+        return dataclasses.replace(
+            self, k_parts=tuple(k_parts), v_parts=tuple(v_parts))
+
+    def append_rows(self, layer: int, slot: jax.Array, k_new: jax.Array,
+                    v_new: jax.Array, n_valid: jax.Array):
+        """Scatter a chunk of consecutive tokens' K/V for one layer into
+        slot ``slot``'s own pages, from position ``lengths[slot]``.
+        k_new: (C, K, hd).  Rows at or past ``n_valid``, and rows at or
+        past the slot's last position, are pushed out of bounds and
+        dropped: clamped, they would land on live rows of the last page."""
+        C = k_new.shape[0]
+        n_pages = self.page_device.shape[1]
+        pos = self.lengths[slot] + jnp.arange(C)
+        page = jnp.minimum(pos // self.page_t, n_pages - 1)
+        dev = self.page_device[slot][page]
+        local = self.page_local[slot][page] * self.page_t + pos % self.page_t
+        keep = (jnp.arange(C) < n_valid) & (pos < n_pages * self.page_t)
+        k_parts = list(self.k_parts)
+        v_parts = list(self.v_parts)
+        for d in range(len(k_parts)):
+            T_d = k_parts[d].shape[2]
+            if T_d == 0:
+                continue
+            idx = jnp.where(keep & (dev == d), local, T_d)
+            k_parts[d] = k_parts[d].at[layer, slot, idx].set(
+                k_new.astype(k_parts[d].dtype), mode="drop")
+            v_parts[d] = v_parts[d].at[layer, slot, idx].set(
                 v_new.astype(v_parts[d].dtype), mode="drop")
         return dataclasses.replace(
             self, k_parts=tuple(k_parts), v_parts=tuple(v_parts))
@@ -1083,6 +1113,33 @@ class TieredKVCache:
 
         return gather(ks), gather(vs), valid
 
+    def ordered_slot(self, layer: int, slot: jax.Array, upto: jax.Array):
+        """:meth:`ordered` cut to slot ``slot``: ``(k, v, valid)`` of one
+        layer, ``(n_pages * page_t, K, hd)`` rows in position order, the
+        positions below ``upto`` valid."""
+        T = self.page_device.shape[1] * self.page_t
+        ks, vs, pos = [], [], []
+        for d in range(len(self.k_parts)):
+            if self.k_parts[d].shape[2]:
+                ks.append(self.k_parts[d][layer, slot])
+                vs.append(self.v_parts[d][layer, slot])
+                p = self.pos_parts[d][slot]
+                pos.append(jnp.where(p < upto, p, T))
+        if self.prefix is not None and self.prefix.pool_pages:
+            k, v, valid = self.prefix.slot_partition(layer, slot)
+            ks.append(k)
+            vs.append(v)
+            pos.append(jnp.where(valid, jnp.arange(valid.shape[0]), T))
+        pos = jnp.concatenate(pos)
+        src = jnp.zeros((T,), jnp.int32).at[pos].set(
+            jnp.arange(pos.shape[0], dtype=jnp.int32), mode="drop")
+        valid = jnp.zeros((T,), bool).at[pos].set(True, mode="drop")
+
+        def gather(parts):
+            return jnp.take(jnp.concatenate(parts), src, axis=0)
+
+        return gather(ks), gather(vs), valid
+
 
 def tiered_decode_step(cfg: ArchConfig, params: dict, cache: TieredKVCache,
                        tokens: jax.Array) -> tuple[jax.Array, TieredKVCache]:
@@ -1119,3 +1176,51 @@ def tiered_decode_step(cfg: ArchConfig, params: dict, cache: TieredKVCache,
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head
     return logits, dataclasses.replace(cache, lengths=cache.lengths + 1)
+
+
+def tiered_prefill_step(cfg: ArchConfig, params: dict, cache: TieredKVCache,
+                        slot: jax.Array, tokens: jax.Array,
+                        n_valid: jax.Array) -> TieredKVCache:
+    """Prefill a chunk of prompt tokens into one slot of a tiered KV cache.
+
+    ``tokens`` is a fixed-length ``(C,)`` block whose first ``n_valid``
+    entries continue slot ``slot``'s sequence from ``lengths[slot]``.  Each
+    layer writes the chunk's K/V rows into the slot's own pages, wherever
+    they are placed, and attends causally over the slot's position-ordered
+    view, with the norms, dtypes and accumulation of
+    :func:`tiered_decode_step`.  The output head is skipped: no logits
+    are read from a prefill.  Returns the cache with only
+    ``lengths[slot]`` advanced, by ``n_valid``."""
+    C = tokens.shape[0]
+    H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    T = cache.page_device.shape[1] * cache.page_t
+    x = jnp.take(params["embed"], tokens, axis=0)
+    pos = cache.lengths[slot] + jnp.arange(C)
+    upto = cache.lengths[slot] + n_valid
+    causal = jnp.arange(T)[None, :] <= pos[:, None]
+
+    for li in range(cfg.n_layers):
+        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+        h = apply_norm(x, lp["ln1"], cfg.norm)
+        q = h @ lp["attn"]["wq"]
+        k = h @ lp["attn"]["wk"]
+        v = h @ lp["attn"]["wv"]
+        if "bq" in lp["attn"]:
+            q, k, v = (q + lp["attn"]["bq"], k + lp["attn"]["bk"],
+                       v + lp["attn"]["bv"])
+        q = q.reshape(C, H, hd)
+        k = k.reshape(C, K, hd)
+        v = v.reshape(C, K, hd)
+        if cfg.rope:
+            q = apply_rope(q, pos, cfg.rope_theta, cfg.rope_pct)
+            k = apply_rope(k, pos, cfg.rope_theta, cfg.rope_pct)
+        cache = cache.append_rows(li, slot, k, v, n_valid)
+        k_all, v_all, valid = cache.ordered_slot(li, slot, upto)
+        o = attn.attend_chunk(q, k_all, v_all,
+                              valid[None, :] & causal).astype(x.dtype)
+        x = x + o.reshape(C, H * hd) @ lp["attn"]["wo"]
+        h = apply_norm(x, lp["ln2"], cfg.norm)
+        x = x + mlp_apply(h, lp["mlp"], cfg.act)
+
+    return dataclasses.replace(
+        cache, lengths=cache.lengths.at[slot].add(n_valid))

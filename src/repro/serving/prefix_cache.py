@@ -112,6 +112,19 @@ class PrefixBlock:
         valid = jnp.repeat(self.slot_pages >= 0, pt, axis=1)
         return k, v, valid
 
+    def slot_partition(self, layer: int, slot: jax.Array):
+        """:meth:`partition` of slot ``slot`` alone: ``(P_max * page_t,
+        K, hd)`` rows and their ``(P_max * page_t,)`` validity."""
+        R = self.k.shape[1]
+        pt = self.page_t
+        Pm = self.slot_pages.shape[1]
+        K, hd = self.k.shape[3:]
+        pages = self.slot_pages[slot]
+        rows = jnp.clip(pages, 0, R - 1)
+        k = jnp.take(self.k[layer], rows, axis=0).reshape(Pm * pt, K, hd)
+        v = jnp.take(self.v[layer], rows, axis=0).reshape(Pm * pt, K, hd)
+        return k, v, jnp.repeat(pages >= 0, pt)
+
 
 class _Node:
     """One trie node == one pool page holding one full token page."""

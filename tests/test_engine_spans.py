@@ -18,7 +18,7 @@ from repro.core.tiers import tpu_v5e_topology
 from repro.models import registry
 from repro.serving.engine import ServingEngine
 
-SPANS = ("engine.prefix.attach", "engine.prefix.promote",
+SPANS = ("engine.prefix.attach", "engine.prefix.promote", "engine.prefill",
          "engine.caption.observe", "engine.caption.actuate",
          "engine.step.inputs", "engine.step.model_time",
          "engine.step.sample")
@@ -93,7 +93,8 @@ def test_every_span_is_recorded_and_no_other(traced):
 
 def test_prefix_spans_carry_the_request_id(traced):
     eng, tokens, events = traced
-    for span in ("engine.prefix.attach", "engine.prefix.promote"):
+    for span in ("engine.prefix.attach", "engine.prefix.promote",
+                 "engine.prefill"):
         rids = [stats.get("rid") for n, stats in events if n == span]
         assert sorted(rids) == sorted(tokens), span
     assert all(r.admitted_at is not None
